@@ -29,6 +29,7 @@ def uniform_average(trees: list[Params]) -> Params:
 
 
 @jax.jit
+@jax.named_scope("aggregate")
 def _wavg_cohorts(stacked_trees: list, ws: list):
     total = sum(w.sum() for w in ws)
 
@@ -54,6 +55,7 @@ def weighted_average_cohorts(stacked_trees: list[Params], weights: list) -> Para
 
 
 @jax.jit
+@jax.named_scope("aggregate")
 def _combine_sums(sums: list, totals: list, like: Params):
     total = totals[0]
     for t in totals[1:]:

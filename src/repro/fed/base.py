@@ -6,6 +6,8 @@ model, partitions, eval batch; only the algorithm and its time profile vary.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,6 +82,7 @@ class BaseTrainer:
         self.wires = codec_lib.wire_sizes(self.costs, self.codec)
         self._ef: dict[int, dict] = {}     # cid -> error-feedback residual
         self.last_uplink_bytes = 0.0
+        self.last_h2d_bytes = 0
 
     def _next_key(self):
         self.key, k = jax.random.split(self.key)
@@ -100,12 +103,14 @@ class BaseTrainer:
         algorithm's time profile."""
         return self._full_model_time(k, self.clients[k].n_batches)
 
+    @functools.partial(jax.profiler.annotate_function, name="fed.plan")
     def plan_round(self, r: int, participants: list[int]) -> RoundPlan:
         self.env.maybe_switch(r)
         trained = list(self.select_clients(r, participants))
         times = np.array([self.client_time(k) for k in trained], float)
         # full-model uplink = one codec'd update upload per trained client
         self.last_uplink_bytes = float(self.wires.full_up * len(trained))
+        self.last_h2d_bytes = 0     # set by the round's training
         return RoundPlan(
             participants=list(participants), trained=trained,
             assign={k: 0 for k in trained}, times=times,
@@ -268,24 +273,24 @@ class BaseTrainer:
         client's (codec'd) upload. The codec's download wire round-trips the
         global before training; the upload wire round-trips the delta."""
         if not hasattr(self, "_full_step"):
-            ad, opt = self.adapter, self.opt
+            full_step = self._full_step_fn()
 
-            @jax.jit
             def step(p, o, batch):
-                loss, g = jax.value_and_grad(lambda q: ad.full_loss(q, batch))(p)
-                p, o = opt.update(p, g, o)
-                return p, o, loss
+                state, loss = full_step({"p": p, "o": o}, batch)
+                return state["p"], state["o"], loss
 
-            self._full_step = step
+            self._full_step = cohort_engine.named_jit(step, f"{self.name}_step")
         ref = self.codec.tree_down_rt(params)             # download wire
         params = ref
         o = self.opt.init(params)
-        for e in range(self.local_epochs):
-            for batch in self.clients[cid].dataset.epoch(
-                r * pipeline.ROUND_SEED_STRIDE + e
-            ):
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
-                params, o, _ = self._full_step(params, o, batch)
+        with jax.profiler.TraceAnnotation("fed.dispatch", tier=0):
+            for e in range(self.local_epochs):
+                for batch in self.clients[cid].dataset.epoch(
+                    r * pipeline.ROUND_SEED_STRIDE + e
+                ):
+                    self.last_h2d_bytes += sum(v.nbytes for v in batch.values())
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                    params, o, _ = self._full_step(params, o, batch)
         ef = self._client_ef(cid) if self.codec.stateful else None
         up, ef2 = codec_lib.uplink_rt_one(self.codec, params, ref, ef)
         if self.codec.stateful:
@@ -300,10 +305,12 @@ class BaseTrainer:
         ad, opt = self.adapter, self.opt
 
         def step(state, batch):
-            loss, g = jax.value_and_grad(
-                lambda q: ad.full_loss(q, batch)
-            )(state["p"])
-            p, o = opt.update(state["p"], g, state["o"])
+            with jax.named_scope("dtfl.client"):
+                loss, g = jax.value_and_grad(
+                    lambda q: ad.full_loss(q, batch)
+                )(state["p"])
+            with jax.named_scope("dtfl.optimizer"):
+                p, o = opt.update(state["p"], g, state["o"])
             return {"p": p, "o": o}, loss
 
         return step
@@ -320,53 +327,49 @@ class BaseTrainer:
         """
         weigh = lambda k: len(self.clients[k].dataset)
         if self.exec_plan.mode == "loop":
+            self.last_h2d_bytes = 0
             locals_ = [self._local_full_steps(r, k, self.params) for k in cids]
-            return aggregation.weighted_average(locals_, [weigh(k) for k in cids])
+            with jax.profiler.TraceAnnotation("fed.aggregate"):
+                return aggregation.weighted_average(
+                    locals_, [weigh(k) for k in cids])
         tier_of = {k: 0 for k in cids}  # untired: bucket by batch shape only
         cohorts = cohort_engine.build_cohorts(
             self.clients, cids, tier_of, r, self.local_epochs,
             pad_multiple=self.exec_plan.pad_multiple,
         )
+        self.last_h2d_bytes = sum(co.nbytes for co in cohorts)
         if self.exec_plan.mode == "sharded":
             sums, totals = [], []
             for co in cohorts:
-                if self.codec.stateful:
-                    ef = self._gather_ef(co)
-                    s, t, ef2 = self._full_sharded_program()(
-                        self.params, co.batches, co.mask,
-                        co.client_weights(self.clients), ef,
-                    )
-                    self._scatter_ef(co, ef2)
-                else:
-                    s, t = self._full_sharded_program()(
-                        self.params, co.batches, co.mask,
-                        co.client_weights(self.clients),
-                    )
+                with jax.profiler.TraceAnnotation("fed.dispatch", tier=co.tier):
+                    if self.codec.stateful:
+                        ef = self._gather_ef(co)
+                        s, t, ef2 = self._full_sharded_program()(
+                            self.params, co.batches, co.mask,
+                            co.client_weights(self.clients), ef,
+                        )
+                        self._scatter_ef(co, ef2)
+                    else:
+                        s, t = self._full_sharded_program()(
+                            self.params, co.batches, co.mask,
+                            co.client_weights(self.clients),
+                        )
                 sums.append(s)
                 totals.append(t)
-            return aggregation.combine_weighted_sums(sums, totals, like=self.params)
+            with jax.profiler.TraceAnnotation("fed.aggregate"):
+                return aggregation.combine_weighted_sums(
+                    sums, totals, like=self.params)
         if not hasattr(self, "_full_cohort_program"):
-            step, opt, codec = self._full_step_fn(), self.opt, self.codec
-
-            def body(params, batches, mask):
-                ref = codec.tree_down_rt(params)          # download wire
-                state = {"p": ref, "o": opt.init(ref)}
-                final, _ = cohort_engine.run_cohort(step, state, batches, mask)
-                return ref, final["p"]
-
-            if codec.stateful:
-                @jax.jit
+            body = self._full_body()
+            if self.codec.stateful:
                 def run(params, batches, mask, ef):
-                    ref, trained = body(params, batches, mask)
-                    up, ef2 = codec_lib.uplink_rt_ef(codec, trained, ref, ef)
-                    return up, ef2
+                    return body(params, batches, mask, ef)
             else:
-                @jax.jit
                 def run(params, batches, mask):
-                    ref, trained = body(params, batches, mask)
-                    return codec_lib.uplink_rt(codec, trained, ref)
+                    return body(params, batches, mask)[0]
 
-            self._full_cohort_program = run
+            self._full_cohort_program = cohort_engine.named_jit(
+                run, f"{self.name}_cohort")
         if self.exec_plan.mode == "chunked":
             # the SAME compiled cohort program, invoked at chunk width with
             # per-chunk outputs reassembled on host: the device training
@@ -379,67 +382,81 @@ class BaseTrainer:
                 chunks = []
                 for sl in cohort_engine.chunk_slices(co.mask.shape[1], cs):
                     b, m = cohort_engine.slice_clients(co.batches, co.mask, sl)
-                    if self.codec.stateful:
-                        cids_c = co.cids[sl.start:min(sl.stop, co.size)]
-                        ef = self._gather_ef_cids(cids_c, pad_to=cs)
-                        up, ef2 = self._full_cohort_program(self.params, b, m, ef)
-                        self._scatter_ef_cids(cids_c, ef2)
-                    else:
-                        up = self._full_cohort_program(self.params, b, m)
-                    chunks.append(jax.tree.map(np.asarray, up))
+                    with jax.profiler.TraceAnnotation("fed.dispatch", tier=co.tier):
+                        if self.codec.stateful:
+                            cids_c = co.cids[sl.start:min(sl.stop, co.size)]
+                            ef = self._gather_ef_cids(cids_c, pad_to=cs)
+                            up, ef2 = self._full_cohort_program(
+                                self.params, b, m, ef)
+                            self._scatter_ef_cids(cids_c, ef2)
+                        else:
+                            up = self._full_cohort_program(self.params, b, m)
+                        chunks.append(jax.tree.map(np.asarray, up))
                 trees.append(jax.tree.map(
                     lambda *xs: np.concatenate(xs)[:co.size], *chunks))
                 ws.append([weigh(k) for k in co.cids])
-            return aggregation.weighted_average_cohorts(trees, ws)
+            with jax.profiler.TraceAnnotation("fed.aggregate"):
+                return aggregation.weighted_average_cohorts(trees, ws)
         trees, ws = [], []
         for co in cohorts:
-            if self.codec.stateful:
-                ef = self._gather_ef(co)
-                up, ef2 = self._full_cohort_program(
-                    self.params, co.batches, co.mask, ef)
-                self._scatter_ef(co, ef2)
-                trees.append(up)
-            else:
-                trees.append(
-                    self._full_cohort_program(self.params, co.batches, co.mask))
+            with jax.profiler.TraceAnnotation("fed.dispatch", tier=co.tier):
+                if self.codec.stateful:
+                    ef = self._gather_ef(co)
+                    up, ef2 = self._full_cohort_program(
+                        self.params, co.batches, co.mask, ef)
+                    self._scatter_ef(co, ef2)
+                    trees.append(up)
+                else:
+                    trees.append(self._full_cohort_program(
+                        self.params, co.batches, co.mask))
             ws.append([weigh(k) for k in co.cids])
-        return aggregation.weighted_average_cohorts(trees, ws)
+        with jax.profiler.TraceAnnotation("fed.aggregate"):
+            return aggregation.weighted_average_cohorts(trees, ws)
+
+    def _full_body(self, axis: str | None = None):
+        """The per-client part of the full-model cohort and sharded
+        programs: download wire, optimizer init, the vmapped scan, upload
+        wire (threading the stateful codec's residuals ``ef``, ``None``
+        otherwise). Returns (uploaded trees, ef), client-stacked."""
+        step, opt, codec = self._full_step_fn(), self.opt, self.codec
+
+        def body(params, batches, mask, ef=None):
+            with jax.named_scope("dtfl.codec"):
+                ref = codec.tree_down_rt(params)          # download wire
+            with jax.named_scope("dtfl.state"):
+                state = {"p": ref, "o": opt.init(ref)}
+            final, _ = cohort_engine.run_cohort(
+                step, state, batches, mask, axis=axis)
+            with jax.named_scope("dtfl.codec"):
+                if codec.stateful:
+                    return codec_lib.uplink_rt_ef(codec, final["p"], ref, ef)
+                return codec_lib.uplink_rt(codec, final["p"], ref), None
+
+        return body
 
     def _full_sharded_program(self):
-        """One jitted shard_map program: the full-model cohort scan with its
-        client axis split over the plan's mesh; the N_k-weighted parameter
-        sum and the weight total leave the device pre-reduced (psum), so
-        per-client trees never materialize on host. Codec wires apply as in
-        the cohort program; error-feedback residuals travel client-sharded."""
+        """One jitted shard_map program, ``<name>_sharded``: the full-model
+        cohort body with its client axis split over the plan's mesh; the
+        N_k-weighted parameter sum and the weight total leave the device
+        pre-reduced (psum), so per-client trees never materialize on host.
+        Error-feedback residuals travel client-sharded."""
         if not hasattr(self, "_full_sharded"):
-            step, opt, plan = self._full_step_fn(), self.opt, self.exec_plan
-            codec = self.codec
+            plan, stateful = self.exec_plan, self.codec.stateful
+            body = self._full_body(axis=plan.axis)
 
-            def train_shard(params, batches, mask):
-                ref = codec.tree_down_rt(params)          # download wire
-                state = {"p": ref, "o": opt.init(ref)}
-                final, _ = cohort_engine.run_cohort(
-                    step, state, batches, mask, axis=plan.axis)
-                return ref, final["p"]
-
-            if codec.stateful:
-                def local(params, batches, mask, weights, ef):
-                    ref, trained = train_shard(params, batches, mask)
-                    up, ef2 = codec_lib.uplink_rt_ef(codec, trained, ref, ef)
-                    return (plan.psum_tree(up, scaled_by=weights),
-                            plan.psum_scalar(weights.sum()), ef2)
-
-                self._full_sharded = jax.jit(plan.shard_cohort_call(
-                    local, n_replicated=1, n_client_extra=1,
-                    n_outs=3, client_outs=1,
-                ))
-            else:
-                def local(params, batches, mask, weights):
-                    ref, trained = train_shard(params, batches, mask)
-                    up = codec_lib.uplink_rt(codec, trained, ref)
-                    return (plan.psum_tree(up, scaled_by=weights),
+            def local(params, batches, mask, weights, *ef):
+                up, ef2 = body(params, batches, mask, *ef)
+                with jax.named_scope("aggregate"):
+                    sums = (plan.psum_tree(up, scaled_by=weights),
                             plan.psum_scalar(weights.sum()))
+                return (sums + (ef2,)) if stateful else sums
 
-                self._full_sharded = jax.jit(
-                    plan.shard_cohort_call(local, n_replicated=1))
+            if stateful:
+                call = plan.shard_cohort_call(
+                    local, n_replicated=1, n_client_extra=1,
+                    n_outs=3, client_outs=1)
+            else:
+                call = plan.shard_cohort_call(local, n_replicated=1)
+            self._full_sharded = cohort_engine.named_jit(
+                call, f"{self.name}_sharded")
         return self._full_sharded

@@ -29,6 +29,7 @@ the cached executable; a changed cohort size retraces.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -52,6 +53,12 @@ class Cohort:
     def size(self) -> int:
         return len(self.cids)
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the host arrays the cohort's program takes in (stacked
+        batches and step mask): what the round copies to the device."""
+        return sum(a.nbytes for a in self.batches.values()) + self.mask.nbytes
+
     def client_weights(self, clients) -> np.ndarray:
         """(size + n_pad,) f32 aggregation weights: N_k for real members, 0
         for pad clients — so on-device weighted sums ignore padding exactly."""
@@ -59,6 +66,7 @@ class Cohort:
         return np.asarray(w, np.float32)
 
 
+@functools.partial(jax.profiler.annotate_function, name="fed.assemble")
 def build_cohorts(
     clients, cids: list[int], tier_of: dict[int, int], r: int, local_epochs: int,
     *, pad_multiple: int = 1,
@@ -134,6 +142,14 @@ def _pad_steps(a: np.ndarray, s_max: int) -> np.ndarray:
 # the vectorized program
 # ---------------------------------------------------------------------------
 
+def named_jit(fn, name: str):
+    """``jax.jit(fn)`` under a stable program name: compile logs and the
+    profiler's ``XLA Modules`` line show ``jit_<name>``, not the name of
+    whatever closure built the program."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
 def broadcast_state(state, n: int):
     """Replicate a single-client state pytree along a new leading axis."""
     return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + jnp.shape(x)), state)
@@ -164,13 +180,15 @@ def run_cohort(step_fn, state, batches, mask, *, axis: str | None = None):
     replicated there while each shard's trained state varies over ``axis``,
     so the scan carry is cast to vary over it up front.
     """
-    stacked = broadcast_state(state, mask.shape[1])
-    if axis is not None:
-        stacked = jax.lax.pcast(stacked, axis, to="varying")
+    with jax.named_scope("dtfl.state"):
+        stacked = broadcast_state(state, mask.shape[1])
+        if axis is not None:
+            stacked = jax.lax.pcast(stacked, axis, to="varying")
 
     def body(s, xs):
         batch, m = xs
         new_s, out = jax.vmap(step_fn)(s, batch)
-        return tree_select(m, new_s, s), out
+        with jax.named_scope("dtfl.state"):
+            return tree_select(m, new_s, s), out
 
     return jax.lax.scan(body, stacked, (batches, mask))

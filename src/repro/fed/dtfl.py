@@ -24,8 +24,18 @@ Per round:
      drive both the simulated times and the scheduler's profile, so
      re-tiering reacts to the compressed compute/comm balance
      (docs/architecture.md §7).
+  6. A profiler trace shows each round as host spans (``fed.round`` over
+     ``fed.plan``, ``fed.assemble``, ``fed.dispatch``, ``fed.aggregate``,
+     ``fed.observe`` and ``fed.eval``) and the per-tier programs
+     (``dtfl_cohort_t<tier>``, ``dtfl_sharded_t<tier>``) split by named
+     scope into ``dtfl.client``, ``dtfl.server``, ``dtfl.optimizer``,
+     ``dtfl.codec`` and ``dtfl.state``; ``RoundLog.h2d_bytes`` counts the
+     input bytes each round copies to the device. With no profiler running
+     a span costs about a microsecond and a scope nothing.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +87,7 @@ class DTFLTrainer:
         self.wires = codec_lib.wire_sizes(self.costs, self.codec)
         self._ef: dict[int, dict] = {}     # cid -> error-feedback residuals
         self.last_uplink_bytes = 0.0
+        self.last_h2d_bytes = 0
         profile = TierProfile.from_cost_table(
             self.costs,
             ref_flops=timemodel.UNIT_FLOPS,
@@ -129,122 +140,119 @@ class DTFLTrainer:
         ad, opt, codec = self.adapter, self.opt, self.codec
 
         def step(state: DTFLStepState, batch: dict):
-            (closs, z), (cg, ag) = jax.value_and_grad(
-                lambda cp, ap: ad.client_loss(cp, ap, batch), argnums=(0, 1),
-                has_aux=True,
-            )(state.client, state.aux)
-            z = codec.tree_rt(jax.lax.stop_gradient(z))
-            sloss, sg = jax.value_and_grad(
-                lambda sp: ad.server_loss(sp, z, batch, tier)
-            )(state.server)
-            c, co = opt.update(state.client, cg, state.c_opt)
-            a, ao = opt.update(state.aux, ag, state.a_opt)
-            s, so = opt.update(state.server, sg, state.s_opt)
+            with jax.named_scope("dtfl.client"):
+                (closs, z), (cg, ag) = jax.value_and_grad(
+                    lambda cp, ap: ad.client_loss(cp, ap, batch), argnums=(0, 1),
+                    has_aux=True,
+                )(state.client, state.aux)
+            with jax.named_scope("dtfl.codec"):
+                z = codec.tree_rt(jax.lax.stop_gradient(z))
+            with jax.named_scope("dtfl.server"):
+                sloss, sg = jax.value_and_grad(
+                    lambda sp: ad.server_loss(sp, z, batch, tier)
+                )(state.server)
+            with jax.named_scope("dtfl.optimizer"):
+                c, co = opt.update(state.client, cg, state.c_opt)
+                a, ao = opt.update(state.aux, ag, state.a_opt)
+                s, so = opt.update(state.server, sg, state.s_opt)
             return DTFLStepState(c, a, s, co, ao, so), (closs, sloss)
 
         return step
 
     def _tier_step(self, tier: int):
         if tier not in self._step_cache:
-            self._step_cache[tier] = jax.jit(self._raw_step(tier))
+            self._step_cache[tier] = cohort_engine.named_jit(
+                self._raw_step(tier), f"dtfl_step_t{tier}")
         return self._step_cache[tier]
 
-    def _cohort_program(self, tier: int):
-        """One jitted program per tier: split + optimizer init + vmapped scan
-        over the cohort's steps + merge, all fused on device (eager per-leaf
-        dispatch is exactly the overhead the engine removes).
+    def _cohort_body(self, tier: int, axis: str | None = None):
+        """The per-client part of the cohort and sharded programs: split +
+        optimizer init + vmapped scan over the cohort's steps + merge.
 
         The codec's download wire round-trips (client half, tier aux head)
         before training and the upload wire round-trips each member's delta
         before the merge; stateful codecs additionally thread the per-client
-        error-feedback residuals through the program."""
-        if tier not in self._cohort_cache:
-            ad, opt, codec = self.adapter, self.opt, self.codec
-            step = self._raw_step(tier)
+        error-feedback residuals ``efc``/``efa`` through (``None`` otherwise).
+        Returns (merged trees, uploaded aux heads, efc, efa), client-stacked."""
+        ad, opt, codec = self.adapter, self.opt, self.codec
+        step = self._raw_step(tier)
 
-            def body(params, aux, batches, mask):
+        def body(params, aux, batches, mask, efc=None, efa=None):
+            with jax.named_scope("dtfl.state"):
                 cp, sp = ad.split(params, tier)
+            with jax.named_scope("dtfl.codec"):
                 cp, auxd = codec.tree_down_rt(cp), codec.tree_down_rt(aux)
+            with jax.named_scope("dtfl.state"):
                 state = DTFLStepState(
                     cp, auxd, sp, opt.init(cp), opt.init(auxd), opt.init(sp)
                 )
-                final, _ = cohort_engine.run_cohort(step, state, batches, mask)
-                return cp, auxd, final
-
-            if codec.stateful:
-                @jax.jit
-                def run(params, aux, batches, mask, efc, efa):
-                    cp, auxd, final = body(params, aux, batches, mask)
-                    upc, efc2 = codec_lib.uplink_rt_ef(codec, final.client, cp, efc)
-                    upa, efa2 = codec_lib.uplink_rt_ef(codec, final.aux, auxd, efa)
-                    merged = jax.vmap(ad.merge)(upc, final.server)
-                    return merged, upa, efc2, efa2
-            else:
-                @jax.jit
-                def run(params, aux, batches, mask):
-                    cp, auxd, final = body(params, aux, batches, mask)
+            final, _ = cohort_engine.run_cohort(step, state, batches, mask,
+                                                axis=axis)
+            with jax.named_scope("dtfl.codec"):
+                if codec.stateful:
+                    upc, efc = codec_lib.uplink_rt_ef(codec, final.client, cp, efc)
+                    upa, efa = codec_lib.uplink_rt_ef(codec, final.aux, auxd, efa)
+                else:
                     upc = codec_lib.uplink_rt(codec, final.client, cp)
                     upa = codec_lib.uplink_rt(codec, final.aux, auxd)
-                    merged = jax.vmap(ad.merge)(upc, final.server)
-                    return merged, upa
+            with jax.named_scope("dtfl.state"):
+                merged = jax.vmap(ad.merge)(upc, final.server)
+            return merged, upa, efc, efa
 
-            self._cohort_cache[tier] = run
+        return body
+
+    def _cohort_program(self, tier: int):
+        """One jitted program per tier, ``dtfl_cohort_t<tier>``: the cohort
+        body fused on device (eager per-leaf dispatch is exactly the overhead
+        the engine removes)."""
+        if tier not in self._cohort_cache:
+            body = self._cohort_body(tier)
+            if self.codec.stateful:
+                def run(params, aux, batches, mask, efc, efa):
+                    return body(params, aux, batches, mask, efc, efa)
+            else:
+                def run(params, aux, batches, mask):
+                    return body(params, aux, batches, mask)[:2]
+
+            self._cohort_cache[tier] = cohort_engine.named_jit(
+                run, f"dtfl_cohort_t{tier}")
         return self._cohort_cache[tier]
 
     def _sharded_program(self, tier: int):
         """The per-tier cohort program with its client axis split across the
-        ExecPlan's mesh via shard_map. Each shard trains its client slice
-        (split + opt init + vmapped scan + merge, exactly the cohort
-        program), then the cross-client FedAvg weighted sums — merged global
-        trees AND tier aux heads — reduce on-device as psum collectives;
-        only (sum_tree, aux_sum_tree, weight_total) leave the mesh."""
+        ExecPlan's mesh via shard_map, ``dtfl_sharded_t<tier>``. Each shard
+        trains its client slice (the cohort body), then the cross-client
+        FedAvg weighted sums — merged global trees AND tier aux heads —
+        reduce on-device as psum collectives; only (sum_tree, aux_sum_tree,
+        weight_total) leave the mesh, with the stateful codec's residuals
+        after them, client-sharded."""
         if tier not in self._sharded_cache:
-            ad, opt, plan, codec = self.adapter, self.opt, self.exec_plan, self.codec
-            step = self._raw_step(tier)
+            plan, stateful = self.exec_plan, self.codec.stateful
+            body = self._cohort_body(tier, axis=plan.axis)
 
-            def train_shard(params, aux, batches, mask):
-                cp, sp = ad.split(params, tier)
-                cp, auxd = codec.tree_down_rt(cp), codec.tree_down_rt(aux)
-                state = DTFLStepState(
-                    cp, auxd, sp, opt.init(cp), opt.init(auxd), opt.init(sp)
-                )
-                final, _ = cohort_engine.run_cohort(
-                    step, state, batches, mask, axis=plan.axis)
-                return cp, auxd, final
-
-            if codec.stateful:
-                def local(params, aux, batches, mask, weights, efc, efa):
-                    cp, auxd, final = train_shard(params, aux, batches, mask)
-                    upc, efc2 = codec_lib.uplink_rt_ef(codec, final.client, cp, efc)
-                    upa, efa2 = codec_lib.uplink_rt_ef(codec, final.aux, auxd, efa)
-                    merged = jax.vmap(ad.merge)(upc, final.server)
-                    return (plan.psum_tree(merged, scaled_by=weights),
-                            plan.psum_tree(upa, scaled_by=weights),
-                            plan.psum_scalar(weights.sum()),
-                            efc2, efa2)
-
-                self._sharded_cache[tier] = jax.jit(plan.shard_cohort_call(
-                    local, n_replicated=2, n_client_extra=2,
-                    n_outs=5, client_outs=2,
-                ))
-            else:
-                def local(params, aux, batches, mask, weights):
-                    cp, auxd, final = train_shard(params, aux, batches, mask)
-                    upc = codec_lib.uplink_rt(codec, final.client, cp)
-                    upa = codec_lib.uplink_rt(codec, final.aux, auxd)
-                    merged = jax.vmap(ad.merge)(upc, final.server)
-                    return (plan.psum_tree(merged, scaled_by=weights),
+            def local(params, aux, batches, mask, weights, *ef):
+                merged, upa, efc, efa = body(params, aux, batches, mask, *ef)
+                with jax.named_scope("aggregate"):
+                    sums = (plan.psum_tree(merged, scaled_by=weights),
                             plan.psum_tree(upa, scaled_by=weights),
                             plan.psum_scalar(weights.sum()))
+                return (sums + (efc, efa)) if stateful else sums
 
-                self._sharded_cache[tier] = jax.jit(
-                    plan.shard_cohort_call(local, n_replicated=2)
-                )
+            local.__name__ = f"dtfl_sharded_t{tier}"
+            if stateful:
+                call = plan.shard_cohort_call(
+                    local, n_replicated=2, n_client_extra=2,
+                    n_outs=5, client_outs=2)
+            else:
+                call = plan.shard_cohort_call(local, n_replicated=2)
+            self._sharded_cache[tier] = cohort_engine.named_jit(
+                call, local.__name__)
         return self._sharded_cache[tier]
 
     # ------------------------------------------------------------------
     # engine hooks (fed/engine.py contract): plan -> execute -> observe
     # ------------------------------------------------------------------
+    @functools.partial(jax.profiler.annotate_function, name="fed.plan")
     def plan_round(self, r: int, participants: list[int]) -> RoundPlan:
         """Profile switching + Algorithm-1 scheduling + analytic Eq.-5 times.
 
@@ -277,6 +285,7 @@ class DTFLTrainer:
         # codec-true client->host bytes of this round (z uplink + update
         # upload), surfaced per round through RoundLog.uplink_bytes
         self.last_uplink_bytes = float(self.wires.uplink_bytes(tiers, nb).sum())
+        self.last_h2d_bytes = 0     # set by the round's training
         self.last_hosts = (None if topo.is_server_only else
                            {k: h for k, h in topo.hosts().items()
                             if h != topology_lib.SERVER})
@@ -293,6 +302,7 @@ class DTFLTrainer:
         self.params = self._train_participants(r, trained, plan.assign)
         return 0.0
 
+    @functools.partial(jax.profiler.annotate_function, name="fed.observe")
     def observe_round(self, plan: RoundPlan, idx: list[int], obs_times, totals) -> None:
         # contract (see fed/engine.py): obs_times is pre-sliced to idx;
         # plan.obs arrays are full-length and sliced here
@@ -351,27 +361,30 @@ class DTFLTrainer:
         cohorts = cohort_engine.build_cohorts(
             self.clients, participants, assign, r, self.local_epochs
         )
+        self.last_h2d_bytes = sum(co.nbytes for co in cohorts)
         for co in cohorts:
-            if self.codec.stateful:
-                efc, efa = self._gather_ef(co)
-                merged, aux, efc2, efa2 = self._cohort_program(co.tier)(
-                    self.params, self.aux[co.tier], co.batches, co.mask,
-                    efc, efa,
-                )
-                self._scatter_ef(co, efc2, efa2)
-            else:
-                merged, aux = self._cohort_program(co.tier)(
-                    self.params, self.aux[co.tier], co.batches, co.mask
-                )
+            with jax.profiler.TraceAnnotation("fed.dispatch", tier=co.tier):
+                if self.codec.stateful:
+                    efc, efa = self._gather_ef(co)
+                    merged, aux, efc2, efa2 = self._cohort_program(co.tier)(
+                        self.params, self.aux[co.tier], co.batches, co.mask,
+                        efc, efa,
+                    )
+                    self._scatter_ef(co, efc2, efa2)
+                else:
+                    merged, aux = self._cohort_program(co.tier)(
+                        self.params, self.aux[co.tier], co.batches, co.mask
+                    )
             w = [len(self.clients[k].dataset) for k in co.cids]
             merged_trees.append(merged)
             merged_ws.append(w)
             aux_by_tier.setdefault(co.tier, []).append((aux, w))
-        for tier, parts in aux_by_tier.items():
-            self.aux[tier] = aggregation.weighted_average_cohorts(
-                [a for a, _ in parts], [w for _, w in parts]
-            )
-        return aggregation.weighted_average_cohorts(merged_trees, merged_ws)
+        with jax.profiler.TraceAnnotation("fed.aggregate"):
+            for tier, parts in aux_by_tier.items():
+                self.aux[tier] = aggregation.weighted_average_cohorts(
+                    [a for a, _ in parts], [w for _, w in parts]
+                )
+            return aggregation.weighted_average_cohorts(merged_trees, merged_ws)
 
     def _train_sharded(self, r, participants, assign):
         """The cohort round with every cohort's client axis sharded over the
@@ -387,27 +400,30 @@ class DTFLTrainer:
             self.clients, participants, assign, r, self.local_epochs,
             pad_multiple=self.exec_plan.pad_multiple,
         )
+        self.last_h2d_bytes = sum(co.nbytes for co in cohorts)
         for co in cohorts:
             w = co.client_weights(self.clients)
-            if self.codec.stateful:
-                efc, efa = self._gather_ef(co)
-                msum, asum, wtot, efc2, efa2 = self._sharded_program(co.tier)(
-                    self.params, self.aux[co.tier], co.batches, co.mask, w,
-                    efc, efa,
-                )
-                self._scatter_ef(co, efc2, efa2)
-            else:
-                msum, asum, wtot = self._sharded_program(co.tier)(
-                    self.params, self.aux[co.tier], co.batches, co.mask, w
-                )
+            with jax.profiler.TraceAnnotation("fed.dispatch", tier=co.tier):
+                if self.codec.stateful:
+                    efc, efa = self._gather_ef(co)
+                    msum, asum, wtot, efc2, efa2 = self._sharded_program(co.tier)(
+                        self.params, self.aux[co.tier], co.batches, co.mask, w,
+                        efc, efa,
+                    )
+                    self._scatter_ef(co, efc2, efa2)
+                else:
+                    msum, asum, wtot = self._sharded_program(co.tier)(
+                        self.params, self.aux[co.tier], co.batches, co.mask, w
+                    )
             sums.append(msum)
             totals.append(wtot)
             aux_by_tier.setdefault(co.tier, []).append((asum, wtot))
-        for tier, parts in aux_by_tier.items():
-            self.aux[tier] = aggregation.combine_weighted_sums(
-                [a for a, _ in parts], [t for _, t in parts], like=self.aux[tier]
-            )
-        return aggregation.combine_weighted_sums(sums, totals, like=self.params)
+        with jax.profiler.TraceAnnotation("fed.aggregate"):
+            for tier, parts in aux_by_tier.items():
+                self.aux[tier] = aggregation.combine_weighted_sums(
+                    [a for a, _ in parts], [t for _, t in parts],
+                    like=self.aux[tier])
+            return aggregation.combine_weighted_sums(sums, totals, like=self.params)
 
     def _train_chunked(self, r, participants, assign):
         """The cohort round with each cohort's client axis cut into
@@ -429,21 +445,23 @@ class DTFLTrainer:
             self.clients, participants, assign, r, self.local_epochs,
             pad_multiple=cs,
         )
+        self.last_h2d_bytes = sum(co.nbytes for co in cohorts)
         for co in cohorts:
             prog = self._cohort_program(co.tier)
             mchunks, achunks = [], []
             for sl in cohort_engine.chunk_slices(co.mask.shape[1], cs):
                 b, m = cohort_engine.slice_clients(co.batches, co.mask, sl)
-                if self.codec.stateful:
-                    cids_c = co.cids[sl.start:min(sl.stop, co.size)]
-                    efc, efa = self._gather_ef_cids(cids_c, co.tier, pad_to=cs)
-                    merged, upa, efc2, efa2 = prog(
-                        self.params, self.aux[co.tier], b, m, efc, efa)
-                    self._scatter_ef_cids(cids_c, co.tier, efc2, efa2)
-                else:
-                    merged, upa = prog(self.params, self.aux[co.tier], b, m)
-                mchunks.append(jax.tree.map(np.asarray, merged))
-                achunks.append(jax.tree.map(np.asarray, upa))
+                with jax.profiler.TraceAnnotation("fed.dispatch", tier=co.tier):
+                    if self.codec.stateful:
+                        cids_c = co.cids[sl.start:min(sl.stop, co.size)]
+                        efc, efa = self._gather_ef_cids(cids_c, co.tier, pad_to=cs)
+                        merged, upa, efc2, efa2 = prog(
+                            self.params, self.aux[co.tier], b, m, efc, efa)
+                        self._scatter_ef_cids(cids_c, co.tier, efc2, efa2)
+                    else:
+                        merged, upa = prog(self.params, self.aux[co.tier], b, m)
+                    mchunks.append(jax.tree.map(np.asarray, merged))
+                    achunks.append(jax.tree.map(np.asarray, upa))
             n = co.size    # reassemble the cohort stack, drop pad columns
             cat = lambda *xs: np.concatenate(xs)[:n]
             merged_trees.append(jax.tree.map(cat, *mchunks))
@@ -451,17 +469,19 @@ class DTFLTrainer:
             merged_ws.append(w)
             aux_by_tier.setdefault(co.tier, []).append(
                 (jax.tree.map(cat, *achunks), w))
-        for tier, parts in aux_by_tier.items():
-            self.aux[tier] = aggregation.weighted_average_cohorts(
-                [a for a, _ in parts], [w for _, w in parts]
-            )
-        return aggregation.weighted_average_cohorts(merged_trees, merged_ws)
+        with jax.profiler.TraceAnnotation("fed.aggregate"):
+            for tier, parts in aux_by_tier.items():
+                self.aux[tier] = aggregation.weighted_average_cohorts(
+                    [a for a, _ in parts], [w for _, w in parts]
+                )
+            return aggregation.weighted_average_cohorts(merged_trees, merged_ws)
 
     def _train_sequential(self, r, participants, assign):
         """Per-client loop (debug escape hatch; O(clients x batches) dispatches)."""
         round_aux = dict(self.aux)  # cohort members share the round-start head
         merged, weights = [], []
         aux_by_tier: dict[int, list] = {}
+        self.last_h2d_bytes = 0
         for k in participants:
             tier = assign[k]
             cl = self.clients[k]
@@ -473,10 +493,12 @@ class DTFLTrainer:
                 self.opt.init(cp), self.opt.init(auxd), self.opt.init(sp),
             )
             step = self._tier_step(tier)
-            for e in range(self.local_epochs):
-                for batch in cl.dataset.epoch(r * pipeline.ROUND_SEED_STRIDE + e):
-                    batch = {k2: jnp.asarray(v) for k2, v in batch.items()}
-                    state, _ = step(state, batch)
+            with jax.profiler.TraceAnnotation("fed.dispatch", tier=tier):
+                for e in range(self.local_epochs):
+                    for batch in cl.dataset.epoch(r * pipeline.ROUND_SEED_STRIDE + e):
+                        self.last_h2d_bytes += sum(v.nbytes for v in batch.values())
+                        batch = {k2: jnp.asarray(v) for k2, v in batch.items()}
+                        state, _ = step(state, batch)
             # upload wire (with error feedback for stateful codecs)
             efc = efa = None
             if self.codec.stateful:
@@ -492,11 +514,12 @@ class DTFLTrainer:
             aux_by_tier.setdefault(tier, []).append((upa, len(cl.dataset)))
             merged.append(self.adapter.merge(upc, state.server))
             weights.append(len(cl.dataset))
-        for tier, parts in aux_by_tier.items():
-            self.aux[tier] = aggregation.weighted_average(
-                [a for a, _ in parts], [w for _, w in parts]
-            )
-        return aggregation.weighted_average(merged, weights)
+        with jax.profiler.TraceAnnotation("fed.aggregate"):
+            for tier, parts in aux_by_tier.items():
+                self.aux[tier] = aggregation.weighted_average(
+                    [a for a, _ in parts], [w for _, w in parts]
+                )
+            return aggregation.weighted_average(merged, weights)
 
     # ------------------------------------------------------------------
     # error-feedback state (stateful codecs): residuals live host-side per

@@ -70,6 +70,10 @@ class RoundLog:
     # None for the classic all-server topology, so server-mode logs are
     # unchanged field-for-field
     hosts: dict[int, int] | None = None
+    # bytes of the host arrays the round's device programs took in (stacked
+    # batches and step masks, ``Cohort.nbytes``; the loop plane's batches);
+    # the trainers set it in training, as last_h2d_bytes
+    h2d_bytes: int = 0
 
 
 @dataclass
@@ -229,17 +233,19 @@ def run_rounds(
     next_round = start_round
     n_part = _round_sample_size(len(trainer.clients), participation, sample_size)
     for r in range(start_round, n_rounds):
-        participants = sorted(
-            rng.choice(len(trainer.clients), n_part, replace=False).tolist()
-        )
-        res = trainer.train_round(r, participants)
-        straggler, assign = res if isinstance(res, tuple) else (res, {})
-        clock += straggler
-        acc = float(eval_fn(trainer.params, eval_batch)) if r % eval_every == 0 else (
-            logs[-1].acc if logs else last_acc)
-        logs.append(RoundLog(r, clock, acc, assign, straggler,
-                             uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
-                             hosts=getattr(trainer, "last_hosts", None)))
+        with jax.profiler.TraceAnnotation("fed.round", round=r):
+            participants = sorted(
+                rng.choice(len(trainer.clients), n_part, replace=False).tolist()
+            )
+            res = trainer.train_round(r, participants)
+            straggler, assign = res if isinstance(res, tuple) else (res, {})
+            clock += straggler
+            acc = _evaluate(eval_fn, trainer, eval_batch) if r % eval_every == 0 else (
+                logs[-1].acc if logs else last_acc)
+            logs.append(RoundLog(r, clock, acc, assign, straggler,
+                                 uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
+                                 hosts=getattr(trainer, "last_hosts", None),
+                                 h2d_bytes=getattr(trainer, "last_h2d_bytes", 0)))
         if on_round is not None:
             on_round(logs[-1])
         next_round = r + 1
@@ -268,9 +274,22 @@ def _eval_setup(trainer, eval_batch):
     # retrace a fresh jit wrapper per run
     fn = getattr(trainer, "_eval_jit", None)
     if fn is None:
-        fn = jax.jit(trainer.adapter.eval_acc)
+        adapter = trainer.adapter
+
+        def eval_acc(params, batch):
+            with jax.named_scope("eval"):
+                return adapter.eval_acc(params, batch)
+
+        fn = jax.jit(eval_acc)
         trainer._eval_jit = fn
     return fn, eval_batch
+
+
+def _evaluate(eval_fn, trainer, eval_batch) -> float:
+    """The round's accuracy: the eval dispatch and its read-back, which
+    waits for the round's device work."""
+    with jax.profiler.TraceAnnotation("fed.eval"):
+        return float(eval_fn(trainer.params, eval_batch))
 
 
 # ===========================================================================
@@ -311,92 +330,94 @@ def run_events(
     next_round = start_round
 
     for r in range(start_round, n_rounds):
-        # no churn: pass the population SIZE, not an arange — choice(int)
-        # consumes the identical rng stream as choice(arange(int)) but stays
-        # O(sample) instead of materializing an O(population) id array
-        pool = churn.begin_round(r) if churn is not None else n_clients
-        pool_n = len(pool) if churn is not None else n_clients
-        cap = (int(participation * n_clients) if sample_size is None
-               else _round_sample_size(n_clients, participation, sample_size))
-        n_part = max(1, min(pool_n, cap))
-        participants = sorted(rng.choice(pool, n_part, replace=False).tolist())
+        with jax.profiler.TraceAnnotation("fed.round", round=r):
+            # no churn: pass the population SIZE, not an arange — choice(int)
+            # consumes the identical rng stream as choice(arange(int)) but stays
+            # O(sample) instead of materializing an O(population) id array
+            pool = churn.begin_round(r) if churn is not None else n_clients
+            pool_n = len(pool) if churn is not None else n_clients
+            cap = (int(participation * n_clients) if sample_size is None
+                   else _round_sample_size(n_clients, participation, sample_size))
+            n_part = max(1, min(pool_n, cap))
+            participants = sorted(rng.choice(pool, n_part, replace=False).tolist())
 
-        plan = trainer.plan_round(r, participants)
-        start = q.now
-        # one completion event per trained client; payload carries the
-        # planned offset so float identity survives absolute-time round trips
-        pending: dict[int, object] = {}
-        for i, k in enumerate(plan.trained):
-            pending[i] = q.push(
-                start + plan.times[i], "complete",
-                cid=k, idx=i, offset=float(plan.times[i]),
-            )
-        if churn is not None:
-            for kind, i, frac in churn.sample_mid_round(plan.trained, plan.times):
-                q.push(start + frac * plan.times[i], kind,
-                       cid=plan.trained[i], idx=i)
-
-        # drain the round: completions, dropouts, mid-round switches
-        survivors: list[int] = []
-        offsets: dict[int, float] = {}
-        while not q.empty():
-            ev = q.pop()
-            i = ev.payload["idx"]
-            if ev.kind == "complete":
-                survivors.append(i)
-                offsets[i] = ev.payload["offset"]
-            elif ev.kind == "dropout":
-                if i in survivors:
-                    continue  # completed before the dropout fired
-                pending[i].cancel()
-                churn.mark_offline(ev.payload["cid"])
-            elif ev.kind == "switch":
-                if i in survivors:
-                    continue
-                cid = ev.payload["cid"]
-                old = trainer.env.profile(cid)
-                churn.resample_profile(trainer.env, cid)
-                new = trainer.env.profile(cid)
-                new_off = timemodel.rescale_remaining(
-                    pending[i].payload["offset"], ev.time - start, old, new
-                )
-                pending[i].cancel()
+            plan = trainer.plan_round(r, participants)
+            start = q.now
+            # one completion event per trained client; payload carries the
+            # planned offset so float identity survives absolute-time round trips
+            pending: dict[int, object] = {}
+            for i, k in enumerate(plan.trained):
                 pending[i] = q.push(
-                    start + new_off, "complete",
-                    cid=cid, idx=i, offset=float(new_off),
+                    start + plan.times[i], "complete",
+                    cid=k, idx=i, offset=float(plan.times[i]),
                 )
+            if churn is not None:
+                for kind, i, frac in churn.sample_mid_round(plan.trained, plan.times):
+                    q.push(start + frac * plan.times[i], kind,
+                           cid=plan.trained[i], idx=i)
 
-        survivors.sort()
-        trained = [plan.trained[i] for i in survivors]
-        extra = trainer.execute_round(r, plan, trained) or 0.0
+            # drain the round: completions, dropouts, mid-round switches
+            survivors: list[int] = []
+            offsets: dict[int, float] = {}
+            while not q.empty():
+                ev = q.pop()
+                i = ev.payload["idx"]
+                if ev.kind == "complete":
+                    survivors.append(i)
+                    offsets[i] = ev.payload["offset"]
+                elif ev.kind == "dropout":
+                    if i in survivors:
+                        continue  # completed before the dropout fired
+                    pending[i].cancel()
+                    churn.mark_offline(ev.payload["cid"])
+                elif ev.kind == "switch":
+                    if i in survivors:
+                        continue
+                    cid = ev.payload["cid"]
+                    old = trainer.env.profile(cid)
+                    churn.resample_profile(trainer.env, cid)
+                    new = trainer.env.profile(cid)
+                    new_off = timemodel.rescale_remaining(
+                        pending[i].payload["offset"], ev.time - start, old, new
+                    )
+                    pending[i].cancel()
+                    pending[i] = q.push(
+                        start + new_off, "complete",
+                        cid=cid, idx=i, offset=float(new_off),
+                    )
 
-        if trained:
-            ratios = np.array(
-                [offsets[i] / plan.times[i] for i in survivors]
-            )
-            totals = np.array([offsets[i] for i in survivors])
-            if plan.obs is not None:
-                obs_t = plan.obs["t"][np.asarray(survivors, int)] * ratios
+            survivors.sort()
+            trained = [plan.trained[i] for i in survivors]
+            extra = trainer.execute_round(r, plan, trained) or 0.0
+
+            if trained:
+                ratios = np.array(
+                    [offsets[i] / plan.times[i] for i in survivors]
+                )
+                totals = np.array([offsets[i] for i in survivors])
+                if plan.obs is not None:
+                    obs_t = plan.obs["t"][np.asarray(survivors, int)] * ratios
+                else:
+                    obs_t = totals
+                trainer.observe_round(plan, survivors, obs_t, totals)
+                base = float(max(offsets[i] for i in survivors)) + extra
             else:
-                obs_t = totals
-            trainer.observe_round(plan, survivors, obs_t, totals)
-            base = float(max(offsets[i] for i in survivors)) + extra
-        else:
-            base = extra  # everyone dropped
-        # the server learns of a dropout at the dropout timestamp, so a round
-        # never ends before the last drained event (q.now)
-        round_end = max(q.now, start + base)
-        straggler = round_end - start
-        q.advance_to(round_end)
+                base = extra  # everyone dropped
+            # the server learns of a dropout at the dropout timestamp, so a round
+            # never ends before the last drained event (q.now)
+            round_end = max(q.now, start + base)
+            straggler = round_end - start
+            q.advance_to(round_end)
 
-        acc = float(eval_fn(trainer.params, eval_batch)) if r % eval_every == 0 else (
-            logs[-1].acc if logs else last_acc
-        )
-        logs.append(RoundLog(r, q.now, acc,
-                             plan.assign if hasattr(trainer, "sched") else {},
-                             straggler,
-                             uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
-                             hosts=_plan_hosts(plan)))
+            acc = _evaluate(eval_fn, trainer, eval_batch) if r % eval_every == 0 else (
+                logs[-1].acc if logs else last_acc
+            )
+            logs.append(RoundLog(r, q.now, acc,
+                                 plan.assign if hasattr(trainer, "sched") else {},
+                                 straggler,
+                                 uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
+                                 hosts=_plan_hosts(plan),
+                                 h2d_bytes=getattr(trainer, "last_h2d_bytes", 0)))
         if on_round is not None:
             on_round(logs[-1])
         next_round = r + 1
@@ -468,21 +489,23 @@ def run_async(
     budget = max_merges if max_merges is not None else max(1, n_rounds) * n_groups
 
     # ---- wave 0: synchronous profiling round (seeds speed estimates) ----
-    pool = churn.begin_round(0) if churn is not None else np.arange(n_clients)
-    n_part = max(1, min(len(pool), int(participation * n_clients)))
-    participants = sorted(rng.choice(pool, n_part, replace=False).tolist())
-    plan0 = trainer.plan_round(0, participants)
-    trainer.execute_round(0, plan0, plan0.trained)
-    idx0 = list(range(len(plan0.trained)))
-    trainer.observe_round(
-        plan0, idx0,
-        plan0.obs["t"] if plan0.obs is not None else plan0.times, plan0.times,
-    )
-    q.advance_to(float(plan0.times.max()))
-    acc = float(eval_fn(trainer.params, eval_batch))
-    logs.append(RoundLog(0, q.now, acc, plan0.assign, float(plan0.times.max()),
-                         uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
-                         hosts=_plan_hosts(plan0)))
+    with jax.profiler.TraceAnnotation("fed.round", round=0):
+        pool = churn.begin_round(0) if churn is not None else np.arange(n_clients)
+        n_part = max(1, min(len(pool), int(participation * n_clients)))
+        participants = sorted(rng.choice(pool, n_part, replace=False).tolist())
+        plan0 = trainer.plan_round(0, participants)
+        trainer.execute_round(0, plan0, plan0.trained)
+        idx0 = list(range(len(plan0.trained)))
+        trainer.observe_round(
+            plan0, idx0,
+            plan0.obs["t"] if plan0.obs is not None else plan0.times, plan0.times,
+        )
+        q.advance_to(float(plan0.times.max()))
+        acc = _evaluate(eval_fn, trainer, eval_batch)
+        logs.append(RoundLog(0, q.now, acc, plan0.assign, float(plan0.times.max()),
+                             uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
+                             hosts=_plan_hosts(plan0),
+                             h2d_bytes=getattr(trainer, "last_h2d_bytes", 0)))
     if on_round is not None:
         on_round(logs[-1])
     if target_acc is not None and acc >= target_acc:
@@ -534,46 +557,52 @@ def run_async(
         # churn inside the wave: dropouts leave the wave, switches re-roll
         # the ground-truth profile for FUTURE waves (the coarse per-group
         # event already fired, so no mid-wave reschedule is needed)
-        idx = list(range(len(plan.trained)))
-        if churn is not None:
-            for kind, i, _ in churn.sample_mid_round(plan.trained, plan.times):
-                if kind == "dropout":
-                    churn.mark_offline(plan.trained[i])
-                    idx.remove(i)
-                else:
-                    churn.resample_profile(trainer.env, plan.trained[i])
-        trained = [plan.trained[i] for i in idx]
-        wave_time = float(plan.times.max())
+        with jax.profiler.TraceAnnotation("fed.round", round=merges + 1):
+            idx = list(range(len(plan.trained)))
+            if churn is not None:
+                for kind, i, _ in churn.sample_mid_round(plan.trained, plan.times):
+                    if kind == "dropout":
+                        churn.mark_offline(plan.trained[i])
+                        idx.remove(i)
+                    else:
+                        churn.resample_profile(trainer.env, plan.trained[i])
+            trained = [plan.trained[i] for i in idx]
+            wave_time = float(plan.times.max())
+            if trained:
+                # train from the wave-launch snapshot (the model the tier
+                # actually downloaded), then restore the merged global
+                current = trainer.params
+                trainer.params = ev.payload["start_params"]
+                try:
+                    tree, w = trainer.train_group(wave_idx[g], plan, trained)
+                finally:
+                    trainer.params = current
+                tier_model[g], tier_weight[g] = tree, w
+                last_merge[g] = version
+                version += 1
+                # staleness-weighted cross-tier merge over groups that reported
+                gs = sorted(tier_model)
+                betas = [
+                    tier_weight[x]
+                    / (1.0 + staleness_lambda * (version - 1 - last_merge[x]))
+                    for x in gs
+                ]
+                with jax.profiler.TraceAnnotation("fed.aggregate"):
+                    trainer.params = aggregation.weighted_average(
+                        [tier_model[x] for x in gs], betas
+                    )
+                obs_t = (plan.obs["t"][np.asarray(idx, int)]
+                         if plan.obs is not None else plan.times[np.asarray(idx, int)])
+                trainer.observe_round(plan, idx, obs_t, plan.times)
+                merges += 1
+                acc = _evaluate(eval_fn, trainer, eval_batch) if (
+                    merges % eval_every == 0) else logs[-1].acc
+                logs.append(RoundLog(
+                    merges, q.now, acc, dict(plan.assign), wave_time,
+                    uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
+                    hosts=_plan_hosts(plan),
+                    h2d_bytes=getattr(trainer, "last_h2d_bytes", 0)))
         if trained:
-            # train from the wave-launch snapshot (the model the tier
-            # actually downloaded), then restore the merged global
-            current = trainer.params
-            trainer.params = ev.payload["start_params"]
-            try:
-                tree, w = trainer.train_group(wave_idx[g], plan, trained)
-            finally:
-                trainer.params = current
-            tier_model[g], tier_weight[g] = tree, w
-            last_merge[g] = version
-            version += 1
-            # staleness-weighted cross-tier merge over groups that reported
-            gs = sorted(tier_model)
-            betas = [
-                tier_weight[x] / (1.0 + staleness_lambda * (version - 1 - last_merge[x]))
-                for x in gs
-            ]
-            trainer.params = aggregation.weighted_average(
-                [tier_model[x] for x in gs], betas
-            )
-            obs_t = (plan.obs["t"][np.asarray(idx, int)]
-                     if plan.obs is not None else plan.times[np.asarray(idx, int)])
-            trainer.observe_round(plan, idx, obs_t, plan.times)
-            merges += 1
-            acc = float(eval_fn(trainer.params, eval_batch)) if (
-                merges % eval_every == 0) else logs[-1].acc
-            logs.append(RoundLog(merges, q.now, acc, dict(plan.assign), wave_time,
-                                 uplink_bytes=getattr(trainer, "last_uplink_bytes", 0.0),
-                                 hosts=_plan_hosts(plan)))
             if on_round is not None:
                 on_round(logs[-1])
             if verbose:

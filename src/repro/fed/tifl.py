@@ -11,6 +11,9 @@ refresh their profile, exactly like a real TiFL server.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import numpy as np
 
 from repro.fed.base import BaseTrainer, RoundPlan
@@ -40,6 +43,7 @@ class TiFLTrainer(BaseTrainer):
         self._round_robin += 1
         return chosen
 
+    @functools.partial(jax.profiler.annotate_function, name="fed.observe")
     def observe_round(self, plan: RoundPlan, idx: list[int], obs_times, totals) -> None:
         for j, i in enumerate(idx):
             self._speed_obs[plan.trained[i]] = float(totals[j])
