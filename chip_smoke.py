@@ -12,11 +12,12 @@ work has finished), the tier assignment, the eval loss and accuracy, and the
 compiles the round triggered. It then checks the chip's numbers: from the
 same seed and with the same tier assignment, one round of the per-client
 ``loop`` plane must update the parameters as one round of the cohort plane
-does, both at HIGHEST matmul precision (see ``FUNC_REL_TOL``).
+does, both at HIGHEST matmul precision with the convolutions as im2col (see
+``FUNC_REL_TOL``).
 
 ``--four-chips`` runs only the sharded plane (client axis over a 4-chip
 mesh) against the cohort plane on the same spec and seed, both at HIGHEST
-precision, compares their parameters, simulated clocks and tiers, and
+precision with the convolutions as im2col, compares their parameters, simulated clocks and tiers, and
 reports how many clients each chip trained.
 
 The script refuses to run without a TPU (no CPU fallback) and ends with one
@@ -26,10 +27,12 @@ before that line. Everything runs in this one process.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
 from pathlib import Path
+from unittest import mock
 
 import jax
 import numpy as np
@@ -65,10 +68,28 @@ SEED = 0
 # The check trains one round per plane: every new cohort shape costs a
 # compile of about two minutes at HIGHEST precision, and round 0 already
 # puts all 4 clients in one cohort (one client per chip on 4 chips).
+#
+# The checks run with the ResNet's convolutions as im2col
+# (``resnet.conv_im2col``), not as the conv primitive the chip's default
+# programs use: at HIGHEST the v5e compiler spends minutes on each conv
+# primitive's weight gradient (a ResNet-110 forward and backward compiled
+# in 850 s, against 71 s as im2col). So these checks do not cover the conv
+# primitive; tests/test_native_conv_planes.py holds the planes to each
+# other under it, on the CPU.
 FUNC_REL_TOL = 5e-2
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+@contextlib.contextmanager
+def highest_with_im2col():
+    """HIGHEST matmul precision, with every conv of the ResNet as im2col."""
+    from repro.models import resnet
+
+    with jax.default_matmul_precision("highest"), \
+            mock.patch.object(resnet, "conv", resnet.conv_im2col):
+        yield
 
 
 def fail(msg: str) -> None:
@@ -146,7 +167,7 @@ def check_round0(label: str, ref_label: str, fed, init, state,
     from repro.models import resnet
 
     images = jax.numpy.asarray(fed.eval_batch["images"])
-    with jax.default_matmul_precision("highest"):
+    with highest_with_im2col():
         fwd = jax.jit(lambda p: resnet.forward(p, fed.cfg, images))
 
         def logits(st):
@@ -204,8 +225,9 @@ def run_federation(label: str, spec, counter):
 
 def one_chip(counter) -> None:
     run_federation("cohort", make_spec("cohort", ROUNDS), counter)
-    say("check: one round of the loop and cohort planes at HIGHEST precision")
-    with jax.default_matmul_precision("highest"):
+    say("check: one round of the loop and cohort planes at HIGHEST precision, "
+        "convs as im2col")
+    with highest_with_im2col():
         coh_fed, init, coh_states = run_federation(
             "cohort@highest", make_spec("cohort", 1), counter)
         loop_fed, loop_init, loop_states = run_federation(
@@ -229,8 +251,8 @@ def four_chips(counter) -> None:
 
     if len(jax.devices()) < 4:
         fail(f"--four-chips needs 4 chips, JAX sees {len(jax.devices())}")
-    say("sharded and cohort planes at HIGHEST precision")
-    with jax.default_matmul_precision("highest"):
+    say("sharded and cohort planes at HIGHEST precision, convs as im2col")
+    with highest_with_im2col():
         coh_fed, init, coh_states = run_federation(
             "cohort@highest", make_spec("cohort", 1), counter)
         sh_fed, sh_init, sh_states = run_federation(

@@ -37,13 +37,37 @@ def conv_init(key, k: int, cin: int, cout: int) -> jax.Array:
 
 
 def conv(x: jax.Array, w: jax.Array, stride: int = 1) -> jax.Array:
-    """SAME conv as im2col + matmul (exactly equals lax.conv_general_dilated).
+    """SAME conv, NHWC input and HWIO weights; the lowering is chosen at
+    trace time from the backend.
 
-    Expressed with slices/pad/dot instead of the conv primitive so that the
-    cohort engine's per-client ``jax.vmap`` lowers to batched GEMMs; vmapping
-    the conv primitive over per-client weights produces grouped convolutions
-    that XLA:CPU executes far slower than the equivalent batched matmuls.
+    On an accelerator it is the conv primitive (``conv_native``). The im2col
+    form writes nine copies of every 3x3 conv's input, each padded to 128
+    lanes on a TPU, and their gradients in the backward pass; the conv
+    primitive reads the input in place, which halves the bytes a ResNet
+    block moves on a v5e. Under the cohort engine's per-client ``jax.vmap``
+    XLA keeps the client axis as a batch dimension of the convolution.
+
+    On the CPU it stays im2col + matmul (``conv_im2col``), so that the CPU
+    tests' bit-equality between execution planes and cohort widths rests on
+    the lowering it was pinned on. The two lowerings agree to float32
+    rounding, and the planes agree under either (``tests/test_resnet.py``,
+    ``tests/test_native_conv_planes.py``).
     """
+    if jax.default_backend() == "cpu":
+        return conv_im2col(x, w, stride)
+    return conv_native(x, w, stride)
+
+
+def conv_native(x: jax.Array, w: jax.Array, stride: int = 1) -> jax.Array:
+    """SAME conv as the conv primitive (SAME puts ``pad // 2`` before, as
+    ``conv_im2col`` does)."""
+    return jax.lax.conv_general_dilated(
+        x, w, (stride, stride), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+
+def conv_im2col(x: jax.Array, w: jax.Array, stride: int = 1) -> jax.Array:
+    """SAME conv as im2col + matmul: pad, take k*k strided slices,
+    concatenate them into k*k*cin channels, one matmul."""
     k, _, cin, cout = w.shape
     _, H, W, _ = x.shape
     if k == 1:

@@ -55,3 +55,66 @@ def test_module_boundaries_cover_all_blocks():
     for cfg in (RESNET56, RESNET110):
         assert R.n_blocks_in_modules(cfg, 7) == cfg.n_blocks
         assert R.n_blocks_in_modules(cfg, 1) == 0  # md1 is the stem only
+
+
+def _assert_close(got, want, rtol=1e-5):
+    """Each leaf within ``rtol`` of the largest magnitude of ``want``'s."""
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape
+        assert np.abs(a - b).max() <= rtol * np.abs(b).max()
+
+
+@pytest.mark.parametrize("cin", [3, 16])
+@pytest.mark.parametrize("hw", [8, 7])
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("k", [1, 3])
+def test_native_conv_equals_im2col(k, stride, hw, cin):
+    """The accelerators' lowering (the conv primitive) computes what the
+    CPU's im2col does, forward and gradient, plain and vmapped over two
+    clients' weights as the cohort engine runs it."""
+    kx, kw = jax.random.split(jax.random.PRNGKey(k * 100 + stride * 10 + hw + cin))
+    x = jax.random.normal(kx, (2, 4, hw, hw, cin))           # (clients, N, H, W, C)
+    w = R.conv_init(kw, k, cin, 8)[None] * jnp.array([1.0, 0.5])[:, None, None, None, None]
+
+    def fwd(conv):
+        return lambda x, w: conv(x, w, stride)
+
+    def grad(conv):
+        return jax.grad(lambda x, w: jnp.sum(jnp.sin(conv(x, w, stride))), argnums=(0, 1))
+
+    for f in (fwd, grad):
+        native, im2col = f(R.conv_native), f(R.conv_im2col)
+        _assert_close(jax.jit(native)(x[0], w[0]), jax.jit(im2col)(x[0], w[0]))
+        _assert_close(jax.jit(jax.vmap(native))(x, w), jax.jit(jax.vmap(im2col))(x, w))
+
+
+@pytest.mark.parametrize("stride", [1, 2])
+@pytest.mark.parametrize("k", [1, 3])
+def test_conv_on_the_cpu_is_im2col(k, stride):
+    """On the CPU backend ``conv`` is the im2col form bit for bit."""
+    assert jax.default_backend() == "cpu"
+    kx, kw = jax.random.split(jax.random.PRNGKey(k + stride))
+    x = jax.random.normal(kx, (2, 4, 9, 9, 16))
+    w = jax.random.normal(kw, (2, k, k, 16, 8))
+    got = jax.jit(jax.vmap(lambda x, w: R.conv(x, w, stride)))(x, w)
+    want = jax.jit(jax.vmap(lambda x, w: R.conv_im2col(x, w, stride)))(x, w)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("backend,precision,native", [
+    ("tpu", None, True),
+    ("tpu", "default", True),
+    ("tpu", "highest", True),
+    ("gpu", "float32", True),
+    ("cpu", None, False),
+])
+def test_conv_lowering_follows_backend_and_precision(backend, precision, native, monkeypatch):
+    """``conv`` takes the conv primitive on any accelerator, whatever the
+    matmul precision, and im2col on the CPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    x, w = jnp.zeros((2, 8, 8, 16)), jnp.zeros((3, 3, 16, 8))
+    with jax.default_matmul_precision(precision):
+        jaxpr = str(jax.make_jaxpr(lambda x, w: R.conv(x, w))(x, w))  # a new trace
+    assert ("conv_general_dilated" in jaxpr) == native
+    assert ("concatenate" in jaxpr) != native
